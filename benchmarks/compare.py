@@ -45,10 +45,12 @@ BENCH_FILES = ("BENCH_fig9.json", "BENCH_fig10.json", "BENCH_replay.json",
 # so old baselines without it still match.  samples_per_s and
 # realized_spi are the serve figure's secondary measurements, and the
 # actor figure's latencies/swap counts are likewise secondary — each
-# gate compares its figure's primary metric only.
+# gate compares its figure's primary metric only.  ``platform`` labels
+# where a point ran; every committed baseline is a CPU point, so it does
+# not split identities (chip numbers live in the perf ledger, not here).
 _MEASUREMENT_FIELDS = {"env_steps_per_s", "replay_ops_per_s",
                        "inserts_per_s", "speedup_vs_sync",
-                       "repeats", "rel_spread",
+                       "repeats", "rel_spread", "platform",
                        "samples_per_s", "realized_spi", "recovery_s",
                        "requests_per_s", "p50_ms", "p99_ms",
                        "p99_before_swap_ms", "p99_after_swap_ms",

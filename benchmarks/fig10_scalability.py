@@ -160,10 +160,13 @@ def pod_sharded_throughput(n_pods: int, n_data: int, compress: bool,
 def _run_worker(worker_args, n_devices, n_envs=16, iters=120):
     """Launch this script as a subprocess with the forced device count
     (the XLA flag must be set before jax initializes) and parse the
-    STEPS_PER_S= line."""
+    STEPS_PER_S= line.  Forced host devices exist only on the CPU, and
+    the parent may hold the accelerator, so the child is pinned to the
+    CPU: its points are CPU points (``platform="cpu"``)."""
     script = os.path.abspath(__file__)
     root = os.path.dirname(os.path.dirname(script))
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"{env.get('XLA_FLAGS', '')} "
         f"--xla_force_host_platform_device_count={n_devices}").strip()
@@ -219,6 +222,7 @@ def shard_pod_points(shard_counts=(1, 2), pod_specs=((2, 1, False),
                                 n_envs=n_envs, iters=iters)
         points.append({"backend": "sharded", "shards": n, "pods": 1,
                        "compressed": False, "n_envs": n_envs,
+                       "platform": "cpu",
                        "env_steps_per_s": round(t, 2),
                        "repeats": REPEATS, "rel_spread": round(spread, 4)})
     for n_pods, n_data, compress in pod_specs:
@@ -227,7 +231,7 @@ def shard_pod_points(shard_counts=(1, 2), pod_specs=((2, 1, False),
             n_pods * n_data, n_envs=n_envs, iters=iters)
         points.append({"backend": "sharded_pod_data", "shards": n_data,
                        "pods": n_pods, "compressed": bool(compress),
-                       "n_envs": n_envs,
+                       "n_envs": n_envs, "platform": "cpu",
                        "env_steps_per_s": round(t, 2),
                        "repeats": REPEATS, "rel_spread": round(spread, 4)})
     return points
@@ -284,7 +288,7 @@ def wallclock_points(specs=WALLCLOCK_SPECS, n_envs=8, iters=40,
             "backend": "wallclock", "shards": n_data, "pods": n_pods,
             "compressed": bool(compress), "overlapped": bool(overlap),
             "n_procs": n_procs, "update_interval": update_interval,
-            "n_envs": n_envs,
+            "n_envs": n_envs, "platform": kv["PLATFORM"],
             "env_steps_per_s": round(float(kv["STEPS_PER_S"]), 2),
             "repeats": int(kv.get("REPEATS", repeats)),
             "rel_spread": round(float(kv.get("REL_SPREAD", 0.0)), 4),

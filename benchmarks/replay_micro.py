@@ -214,12 +214,13 @@ def compiled_fused_record():
     Interpret mode inverts the fused kernel's advantage (the committed
     arms above: fused ≈ 4× slower than split on CPU), so the only fair
     measurement is a compiled one.  On TPU this returns a measured
-    sample+gather rate; on CPU Pallas refuses to lower ("Only interpret
-    mode is supported on CPU backend") and the record carries the error
-    instead — which is exactly why ``ReplayConfig.fused_sample_gather``
-    defaults to backend-appropriate
-    (``tree_ops.default_fused_sample_gather``): fused only where it
-    compiles.
+    sample+gather rate, and a kernel that fails there raises; on CPU
+    Pallas refuses to lower ("Only interpret mode is supported on CPU
+    backend") and the record carries that refusal instead — which is
+    why ``ReplayConfig.fused_sample_gather`` defaults to
+    backend-appropriate (``tree_ops.default_fused_sample_gather``):
+    fused only where it compiles.  The refusal says nothing about the
+    chip.
     """
     from repro.core import sumtree
     from repro.kernels import ops as kops
@@ -261,7 +262,9 @@ def compiled_fused_record():
         samples.sort()
         record["compiled"] = True
         record["sample_gather_per_s"] = round(samples[len(samples) // 2], 2)
-    except Exception as e:  # noqa: BLE001 — the refusal IS the result
+    except Exception as e:  # noqa: BLE001 — the CPU refusal is the result
+        if backend == "tpu":
+            raise
         record["compiled"] = False
         record["error"] = f"{type(e).__name__}: {e}"[:300]
     return record

@@ -138,6 +138,9 @@ def main() -> None:
                          "OS process per worker, gloo collectives)")
     args = ap.parse_args()
 
+    from repro import compile_cache
+
+    compile_cache.enable()
     failed = []
     if args.emit_json:
         try:

@@ -71,6 +71,9 @@ POINT_FIELDS: Dict[str, Dict[str, tuple]] = {
         "n_procs": (int, False),
         "overlapped": (bool, False),
         "update_interval": (int, False),
+        # the JAX platform of a point measured in a child process pinned
+        # to the host (forced host devices, gloo gangs): "cpu"
+        "platform": (str, False),
     },
     # replay-service throughput (benchmarks/fig_serve.py): sustained
     # insert and sample rates of the sharded rate-limited ReplayService

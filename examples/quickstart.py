@@ -108,6 +108,7 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro import compile_cache
     from repro.agents.dqn import DQNConfig, make_dqn
     from repro.core.distributed import (ShardedPrioritizedReplay,
                                         ShardedReplayConfig)
@@ -119,6 +120,7 @@ def main():
                                          executor_from_plan)
     from repro.runtime.loop import LoopConfig
 
+    compile_cache.enable()
     env_fn = functools.partial(make_vec, "cartpole")
     spec, _, _ = env_fn(1)
     agent = make_dqn(spec, DQNConfig(double_q=True))

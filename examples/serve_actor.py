@@ -46,10 +46,12 @@ def main():
 
     import jax
 
+    from repro import compile_cache
     from repro.configs import get_config
     from repro.models import backbone
     from repro.serve import ActorServeConfig, ActorServer, SUPPORTED_FAMILIES
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family not in SUPPORTED_FAMILIES:
         print(f"{cfg.name}: family {cfg.family!r} is not servable — the "

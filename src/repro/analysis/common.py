@@ -96,8 +96,8 @@ def enclosing_function(node: ast.AST) -> Optional[ast.AST]:
 
 def collect_aliases(tree: ast.AST) -> Dict[str, str]:
     """Imported-name → fully dotted target, so ``qualname`` can resolve
-    ``jnp.where`` → ``jax.numpy.where`` and ``shard_map`` →
-    ``jax.experimental.shard_map.shard_map``."""
+    ``jnp.where`` → ``jax.numpy.where`` and ``P`` →
+    ``jax.sharding.PartitionSpec``."""
     aliases: Dict[str, str] = {}
     for node in ast.walk(tree):
         if isinstance(node, ast.Import):

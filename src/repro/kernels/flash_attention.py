@@ -40,6 +40,8 @@ _STUB = os.environ.get("REPRO_FLASH_STUB") == "1"
 BQ = 512
 BK = 512
 NEG = -1e30
+# the (1,) int32 per-layer global flag is a scalar read: keep it in SMEM
+_GLOB_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _block_mask(attention, window, causal, glob, q_pos, k_pos):
@@ -110,7 +112,7 @@ def _fwd_kernel(attention, window, causal, scale,
     def _finalize():
         lsum = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / lsum).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(lsum))[:, 0]
+        lse_ref[0] = m_scr[...] + jnp.log(lsum)          # (BQ, 1)
 
 
 def _fwd(q, k, v, glob, attention, window, causal, bq, bk, interpret):
@@ -124,18 +126,18 @@ def _fwd(q, k, v, glob, attention, window, causal, bq, bk, interpret):
         functools.partial(_fwd_kernel, attention, window, causal, scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (0,)),
+            _GLOB_SPEC,
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, s, hd), q.dtype),
-            jax.ShapeDtypeStruct((n, s), jnp.float32),
+            jax.ShapeDtypeStruct((n, s, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq_, 1), jnp.float32),
@@ -170,8 +172,8 @@ def _dq_kernel(attention, window, causal, scale,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0]                                 # (BQ, 1)
+        delta = delta_ref[0]
         s = jax.lax.dot(q, k.T) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -209,8 +211,8 @@ def _dkv_kernel(attention, window, causal, scale,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0]                                 # (BQ, 1)
+        delta = delta_ref[0]
         s = jax.lax.dot(q, k.T) * scale
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
@@ -232,19 +234,20 @@ def _bwd(attention, window, causal, bq, bk, interpret, res, do):
     sk = k.shape[1]
     bq_, bk_ = min(bq, s), min(bk, sk)
     scale = 1.0 / math.sqrt(hd)
-    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), -1)
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), -1,
+                    keepdims=True)                      # (N, S, 1)
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, attention, window, causal, scale),
         grid=(n, s // bq_, sk // bk_),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (0,)),
+            _GLOB_SPEC,
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, i)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, i)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -256,13 +259,13 @@ def _bwd(attention, window, causal, bq, bk, interpret, res, do):
         functools.partial(_dkv_kernel, attention, window, causal, scale),
         grid=(n, sk // bk_, s // bq_),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, i, j: (0,)),
+            _GLOB_SPEC,
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bq_, hd), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, j)),
-            pl.BlockSpec((1, bq_), lambda b, i, j: (b, j)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, bq_, 1), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk_, hd), lambda b, i, j: (b, i, 0)),

@@ -30,10 +30,10 @@ def _kernel(idx_ref, storage_ref, out_ref):
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    idx = idx_ref[...]                               # (BB,) global indices
+    idx = idx_ref[...]                               # (BB, 1) global indices
     local = idx - n_step * nb                        # position inside block
     niota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], nb), 1)
-    onehot = (local[:, None] == niota).astype(jnp.float32)  # 0 if out of block
+    onehot = (local == niota).astype(jnp.float32)    # 0 if out of block
     block = storage_ref[...].astype(jnp.float32)     # (NB, F)
     acc = jax.lax.dot(onehot, block, precision=jax.lax.Precision.HIGHEST)
     out_ref[...] = out_ref[...] + acc.astype(out_ref.dtype)
@@ -61,10 +61,11 @@ def gather_rows(
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((BATCH_BLOCK,), lambda i, j: (i,)),
+            pl.BlockSpec((BATCH_BLOCK, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((STORAGE_BLOCK, f), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((BATCH_BLOCK, f), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, f), out_dtype),
         interpret=interpret,
-    )(idx, storage)
+        name="gather_rows",
+    )(idx.reshape(b, 1), storage)

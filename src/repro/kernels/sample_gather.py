@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.sumtree_sample import descend
+from repro.kernels.sumtree_sample import (compiler_params, descend,
+                                          descent_vmem_bytes, resident_spec)
 
 SAMPLE_BLOCK = 128   # SB — draws per sample block
 STORAGE_BLOCK = 512  # NB — storage rows per streaming step
@@ -65,10 +66,10 @@ def _kernel(capacity: int, fanout: int, n_levels: int,
 
     # idx comes from the pinned output block (same block ∀ storage steps)
     # — written above at step 0, persistent across the inner grid axis.
-    idx = out_idx_ref[...]
+    idx = out_idx_ref[...]                                  # (SB, 1)
     local = idx - n_step * nb
     niota = jax.lax.broadcasted_iota(jnp.int32, (sb, nb), 1)
-    onehot = (local[:, None] == niota).astype(jnp.float32)  # 0 out of block
+    onehot = (local == niota).astype(jnp.float32)           # 0 out of block
     for s_ref, g_ref in zip(storage_refs, gathered_refs):
         block = s_ref[...].astype(jnp.float32)              # (NB, F)
         acc = jax.lax.dot(onehot, block,
@@ -100,8 +101,6 @@ def sample_gather_levels(
     assert all(m.shape[0] == n for m in storage_mats)
     grid = (b // SAMPLE_BLOCK, n // STORAGE_BLOCK)
 
-    level_specs = [pl.BlockSpec(lv.shape, lambda i, j: (0, 0))
-                   for lv in levels]
     storage_specs = [
         pl.BlockSpec((STORAGE_BLOCK, m.shape[1]), lambda i, j: (j, 0))
         for m in storage_mats
@@ -110,23 +109,27 @@ def sample_gather_levels(
         pl.BlockSpec((SAMPLE_BLOCK, m.shape[1]), lambda i, j: (i, 0))
         for m in storage_mats
     ]
+    col = pl.BlockSpec((SAMPLE_BLOCK, 1), lambda i, j: (i, 0))
     out_shapes = (
-        [jax.ShapeDtypeStruct((b,), jnp.int32),
-         jax.ShapeDtypeStruct((b,), jnp.float32)]
+        [jax.ShapeDtypeStruct((b, 1), jnp.int32),
+         jax.ShapeDtypeStruct((b, 1), jnp.float32)]
         + [jax.ShapeDtypeStruct((b, m.shape[1]), jnp.float32)
            for m in storage_mats]
     )
+    # streamed storage blocks and gathered accumulators are
+    # double-buffered (F lanes pad to 128 in VMEM)
+    stream = sum(2 * (STORAGE_BLOCK + SAMPLE_BLOCK) * pl.cdiv(m.shape[1], 128)
+                 * 128 * 4 for m in storage_mats)
     out = pl.pallas_call(
         functools.partial(_kernel, capacity, fanout, len(levels)),
         grid=grid,
-        in_specs=([pl.BlockSpec((SAMPLE_BLOCK,), lambda i, j: (i,))]
-                  + level_specs + storage_specs),
-        out_specs=[
-            pl.BlockSpec((SAMPLE_BLOCK,), lambda i, j: (i,)),
-            pl.BlockSpec((SAMPLE_BLOCK,), lambda i, j: (i,)),
-        ] + gathered_specs,
+        in_specs=([col] + [resident_spec(lv.shape) for lv in levels]
+                  + storage_specs),
+        out_specs=[col, col] + gathered_specs,
         out_shape=out_shapes,
+        compiler_params=compiler_params(descent_vmem_bytes(levels) + stream),
         interpret=interpret,
-    )(u, *levels, *storage_mats)
+        name="sample_gather",
+    )(u.reshape(b, 1), *levels, *storage_mats)
     idx, pri, *gathered = out
-    return idx, pri, gathered
+    return idx[:, 0], pri[:, 0], gathered

@@ -24,11 +24,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.sumtree_sample import compiler_params
+
 UPDATE_BLOCK = 128  # UB — updates per grid step
 
 
 def _kernel(fanout: int, idx_ref, val_ref, mask_ref, *refs):
-    """refs = (root_out, level_1_out, ..., level_H_out), aliased to inputs."""
+    """refs = (root_out, level_1_out, ..., level_H_out), aliased to inputs.
+    Per-update vectors are (UB, 1) columns."""
     root_ref = refs[0]
     level_refs = refs[1:]
     k = fanout
@@ -50,13 +53,13 @@ def _kernel(fanout: int, idx_ref, val_ref, mask_ref, *refs):
     g = idx // k
     c = idx % k
     giota = jax.lax.broadcasted_iota(jnp.int32, (ub, g_h), 1)
-    oh_g = (g[:, None] == giota).astype(jnp.float32)       # (UB, G_H)
-    oh_c = (c[:, None] == lane).astype(jnp.float32)        # (UB, K)
+    oh_g = (g == giota).astype(jnp.float32)                 # (UB, G_H)
+    oh_c = (c == lane).astype(jnp.float32)                  # (UB, K)
     rows = jax.lax.dot(oh_g, leaf, precision=jax.lax.Precision.HIGHEST)
-    old = jnp.sum(rows * oh_c, axis=-1)
-    delta = (val - old) * mask
+    old = jnp.sum(rows * oh_c, axis=-1, keepdims=True)
+    delta = (val - old) * mask                              # (UB, 1)
     scat = jax.lax.dot(                                     # (G_H, K) scatter
-        oh_g.T, delta[:, None] * oh_c, precision=jax.lax.Precision.HIGHEST
+        oh_g.T, delta * oh_c, precision=jax.lax.Precision.HIGHEST
     )
     leaf_ref[...] = (leaf + scat).astype(leaf_ref.dtype)
 
@@ -68,10 +71,10 @@ def _kernel(fanout: int, idx_ref, val_ref, mask_ref, *refs):
         g2 = node // k
         c2 = node % k
         giota2 = jax.lax.broadcasted_iota(jnp.int32, (ub, g_l), 1)
-        oh_g2 = (g2[:, None] == giota2).astype(jnp.float32)
-        oh_c2 = (c2[:, None] == lane).astype(jnp.float32)
+        oh_g2 = (g2 == giota2).astype(jnp.float32)
+        oh_c2 = (c2 == lane).astype(jnp.float32)
         scat2 = jax.lax.dot(
-            oh_g2.T, delta[:, None] * oh_c2, precision=jax.lax.Precision.HIGHEST
+            oh_g2.T, delta * oh_c2, precision=jax.lax.Precision.HIGHEST
         )
         ref[...] = (lv + scat2).astype(ref.dtype)
         node = g2
@@ -103,20 +106,23 @@ def sumtree_update_levels(
     """
     b = idx.shape[0]
     assert b % UPDATE_BLOCK == 0, b
-    grid = (b // UPDATE_BLOCK,)
-
     tree_in = [root, *levels]
+    # the tree is an accumulator revisited by every grid step, so its
+    # blocks stay double-buffered (in and out); add the (UB, G) one-hots
+    # and the (G, K) scatter of the widest level
+    tree_bytes = sum(t.size for t in tree_in) * 4
+    widest = max(t.shape[0] for t in tree_in)
+    vmem = 4 * tree_bytes + 3 * widest * max(UPDATE_BLOCK, fanout) * 4
     tree_specs = [pl.BlockSpec(t.shape, lambda i: (0, 0)) for t in tree_in]
+    col = pl.BlockSpec((UPDATE_BLOCK, 1), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_kernel, fanout),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((UPDATE_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((UPDATE_BLOCK,), lambda i: (i,)),
-            pl.BlockSpec((UPDATE_BLOCK,), lambda i: (i,)),
-        ] + tree_specs,
+        grid=(b // UPDATE_BLOCK,),
+        in_specs=[col, col, col] + tree_specs,
         out_specs=tree_specs,
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype) for t in tree_in],
         input_output_aliases={3 + j: j for j in range(len(tree_in))},
+        compiler_params=compiler_params(vmem),
         interpret=interpret,
-    )(idx, values, mask, *tree_in)
+        name="sumtree_update",
+    )(idx.reshape(b, 1), values.reshape(b, 1), mask.reshape(b, 1), *tree_in)

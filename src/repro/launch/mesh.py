@@ -15,21 +15,6 @@ import numpy as np
 from repro.models.config import ShardingConfig
 
 
-def use_mesh(mesh):
-    """Version-compatible "make this the ambient mesh" context manager.
-
-    JAX has renamed this three times: ``jax.sharding.use_mesh`` (0.5.x),
-    ``jax.set_mesh`` (0.6+), and on older releases the ``Mesh`` object is
-    itself the context manager.  Callers write ``with use_mesh(m):``
-    regardless of the installed version.
-    """
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
 def data_mesh(n_shards: Optional[int] = None, axis: str = "data"):
     """1-D mesh over ``n_shards`` devices for the sharded replay/learner
     data path (defaults to all visible devices)."""

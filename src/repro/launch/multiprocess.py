@@ -91,17 +91,46 @@ def _src_root() -> str:
         os.path.abspath(__file__))))
 
 
-def worker_env(devices_per_proc: int) -> Dict[str, str]:
-    """Child env: forced per-process host device count (before any jax
-    import — the whole reason the launcher is a separate process) and an
-    import path that reaches ``repro`` regardless of the parent's cwd."""
+def worker_env(devices_per_proc: int = 1,
+               platform: Optional[str] = "cpu") -> Dict[str, str]:
+    """Child env: an import path that reaches ``repro`` regardless of the
+    parent's cwd, and the child's JAX platform.
+
+    ``platform="cpu"`` pins the child to the host with a forced
+    per-process device count (set before any jax import — the whole
+    reason the launcher is a separate process): the role of a CPU
+    emulation.  ``platform=None`` leaves the choice to JAX, so the child
+    takes the accelerator when one is present — unless this process
+    already holds it (one process per chip), in which case the child is
+    pinned to the CPU as well."""
     env = os.environ.copy()
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                        f"{devices_per_proc}")
-    env["JAX_PLATFORMS"] = "cpu"
+    if platform is None and _holds_accelerator():
+        platform = "cpu"
+    if platform is not None:
+        env["JAX_PLATFORMS"] = platform
+        env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
+                            f"{devices_per_proc}")
     path = env.get("PYTHONPATH", "")
     env["PYTHONPATH"] = _src_root() + (os.pathsep + path if path else "")
     return env
+
+
+def _holds_accelerator() -> bool:
+    """True when this process has started a JAX backend other than the
+    CPU — it then owns the chip until it exits."""
+    if "jax" not in sys.modules:
+        return False
+    from jax._src import xla_bridge
+
+    return (xla_bridge.backends_are_initialized()
+            and xla_bridge.get_backend().platform != "cpu")
+
+
+def print_platform() -> None:
+    """Every gang role reports the platform its JAX runs on."""
+    import jax
+
+    print(f"PLATFORM={jax.devices()[0].platform}", flush=True)
 
 
 def launch(
@@ -119,7 +148,9 @@ def launch(
     if n_procs < 1:
         raise ValueError(f"n_procs={n_procs}: need ≥ 1")
     coordinator = coordinator or f"127.0.0.1:{free_port()}"
-    env = worker_env(devices_per_proc)
+    # the wall-clock gang is a CPU emulation: gloo collectives between
+    # host processes
+    env = worker_env(devices_per_proc, platform="cpu")
     procs = []
     for pid in range(n_procs):
         cmd = [sys.executable, "-m", "repro.launch.multiprocess",
@@ -250,11 +281,14 @@ def launch_service(
         raise ValueError("restart_server_after requires snapshot_dir and "
                          "snapshot_every_appends (the restarted server "
                          "restores from the shard snapshots)")
-    env = worker_env(1)
+    # the learner does the SGD — the accelerator side of the paper's
+    # split — and takes the chip when one is present; the server and the
+    # actors are host roles, pinned to the CPU by name
+    host_env, learner_env = worker_env(1, "cpu"), worker_env(1, None)
     port = free_port()
     deadline = time.monotonic() + timeout_s
 
-    def spawn(role_args: List[str]) -> subprocess.Popen:
+    def spawn(role_args: List[str], env=host_env) -> subprocess.Popen:
         cmd = [sys.executable, "-m", "repro.launch.multiprocess", *role_args]
         return subprocess.Popen(cmd, env=env, stdout=subprocess.PIPE,
                                 stderr=subprocess.STDOUT, text=True)
@@ -302,7 +336,8 @@ def launch_service(
                              "--ckpt-every", str(ckpt_every)]
         if restart_learner_after is not None:
             first = spawn([*learner_args,
-                           "--exit-after", str(restart_learner_after)])
+                           "--exit-after", str(restart_learner_after)],
+                          learner_env)
             procs["learner-0"] = first
             first.wait(timeout=max(1.0, deadline - time.monotonic()))
             if first.returncode != 0:
@@ -311,9 +346,10 @@ def launch_service(
                 raise RuntimeError(
                     f"pre-restart learner failed (code {first.returncode}); "
                     f"output tail:\n{tail}")
-            procs["learner"] = spawn([*learner_args, "--resume"])
+            procs["learner"] = spawn([*learner_args, "--resume"],
+                                     learner_env)
         else:
-            procs["learner"] = spawn(learner_args)
+            procs["learner"] = spawn(learner_args, learner_env)
         if restart_server_after is not None:
             from repro.service.faults import CRASH_EXIT_CODE
             first_server = procs.pop("server")
@@ -547,7 +583,6 @@ def _equiv_worker(args):
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.runtime.learner import make_grad_reducer
@@ -591,9 +626,9 @@ def _equiv_worker(args):
         tele = jnp.max(jnp.abs(cum_diff - (varying[-1] - vb[-1])))
         return jax.lax.pmax(shift, "pod"), jax.lax.pmax(tele, "pod")
 
-    run = jax.jit(shard_map(
+    run = jax.jit(jax.shard_map(
         program, mesh=mesh, in_specs=(P("pod"), P(None, "pod")),
-        out_specs=(P(), P()), check_rep=False))
+        out_specs=(P(), P()), check_vma=False))
 
     # identical host-side streams on every process, sharded pod-major
     dim = 16
@@ -1036,6 +1071,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
         # service roles never join jax.distributed: independent runtimes
         # meeting only at the TCP boundary (a dead actor cannot wedge a
         # collective — there are none)
+        print_platform()
         service_roles[args.mode](args)
         return
     if args.coordinator is None:
@@ -1045,6 +1081,7 @@ def main(argv: Optional[Sequence[str]] = None) -> None:
 
     initialize_distributed(args.coordinator, args.n_procs, args.process_id,
                            timeout_s=args.handshake_timeout)
+    print_platform()
     {"bench": _bench_worker,
      "fused": _fused_worker,
      "equiv": _equiv_worker}[args.mode](args)

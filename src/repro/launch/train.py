@@ -38,6 +38,8 @@ import os
 import sys
 import time
 
+from repro.compile_cache import CHECKOUT
+
 
 def _make_param_averager(n_procs: int):
     """Cross-process parameter mean for the wall-clock gang: each worker
@@ -48,7 +50,6 @@ def _make_param_averager(n_procs: int):
     device→host→gloo→device, not an XLA alias."""
     import jax
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
     mesh = Mesh(np.asarray(jax.devices()).reshape(n_procs), ("proc",))
@@ -60,9 +61,9 @@ def _make_param_averager(n_procs: int):
         # repro-lint: disable=C202(local one-axis gang mesh, not the pod/data/model training mesh)
         return jax.tree.map(lambda x: jax.lax.pmean(x[0], "proc"), tree)
 
-    reduce_fn = jax.jit(shard_map(
+    reduce_fn = jax.jit(jax.shard_map(
         pmean, mesh=mesh, in_specs=PartitionSpec("proc"),
-        out_specs=PartitionSpec(), check_rep=False))
+        out_specs=PartitionSpec(), check_vma=False))
 
     def to_global(leaf):
         shape = (n_procs,) + leaf.shape
@@ -101,7 +102,9 @@ def main():
                          "controller SPMD over gloo) instead of the "
                          "in-process run; params are data-parallel-"
                          "averaged across the gang every step")
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir",
+                    default=os.path.join(CHECKOUT, "out", "train_ckpt"),
+                    help="checkpoints; a run resumes from the latest one")
     ap.add_argument("--ckpt-every", type=int, default=25)
     args = ap.parse_args()
 
@@ -119,7 +122,7 @@ def main():
         argv = list(sys.argv[1:])
         i = argv.index("--wall-clock")
         del argv[i:i + 2]
-        env = mp.worker_env(devices_per_proc=1)
+        env = mp.worker_env(devices_per_proc=1, platform="cpu")  # gloo
         env["REPRO_WC_COORD"] = coordinator
         env["REPRO_WC_NPROCS"] = str(n)
         import subprocess
@@ -176,17 +179,19 @@ def main():
     import jax
     import jax.numpy as jnp
 
+    from repro import compile_cache
     from repro.agents import token_dqn
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs import get_config
     from repro.core.replay import PrioritizedReplay, ReplayConfig
     from repro.envs.token_mdp import TokenMDPSpec, make
     from repro.launch.mesh import (make_production_mesh, mesh_from_plan,
-                                   sharding_config, use_mesh)
+                                   sharding_config)
     from repro.models import backbone
     from repro.models.config import NO_SHARDING
     from repro.optim import adam
 
+    compile_cache.enable()
     cfg = get_config(args.arch, smoke=args.smoke)
     if plan is not None:
         # the planned (pod×)data mesh becomes the ambient mesh; the
@@ -208,7 +213,8 @@ def main():
     n_params = sum(x.size for x in jax.tree.leaves(state.params))
     mesh_desc = (f"plan:{plan.n_pods}x{plan.n_data}" if plan is not None
                  else args.mesh)
-    print(f"arch={cfg.name} params={n_params/1e6:.1f}M mesh={mesh_desc}")
+    print(f"arch={cfg.name} params={n_params/1e6:.1f}M mesh={mesh_desc} "
+          f"platform={jax.devices()[0].platform}")
 
     mdp = TokenMDPSpec(vocab=cfg.vocab_size)
     reset, step_env, optimal = make(mdp, jax.random.fold_in(key, 1), args.n_envs)
@@ -260,7 +266,7 @@ def main():
     stack = contextlib.ExitStack()
     if plan is not None and mesh is not None:
         # planned data mesh as the ambient mesh for the training steps
-        stack.enter_context(use_mesh(mesh))
+        stack.enter_context(jax.set_mesh(mesh))
 
     ctx = None
     t0 = time.time()

@@ -242,10 +242,10 @@ def _attn_flash(cfg, shd, q, k, v, is_global, causal):
         except Exception:
             mesh = None
     if mesh is not None:
-        from jax.experimental.shard_map import shard_map
         spec = P(dp(shd), None, shd.tp, None)
-        out = shard_map(local, mesh=mesh, in_specs=(spec,) * 3 + (P(None),),
-                        out_specs=spec, check_rep=False)(q, k, v, glob)
+        out = jax.shard_map(local, mesh=mesh,
+                            in_specs=(spec,) * 3 + (P(None),),
+                            out_specs=spec, check_vma=False)(q, k, v, glob)
     else:
         out = local(q, k, v, glob)
     return out[:, :, :h, :]

@@ -77,7 +77,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.agents.base import Agent
@@ -110,6 +109,15 @@ class Executor:
 
         Compiled programs are cached per distinct ``length`` — the run
         loop only ever uses ``scan_chunk`` plus one tail length."""
+        return self._chunk(length)(state)
+
+    def lower_chunk(self, state: LoopState):
+        """The ``scan_chunk``-long program ``run_chunk`` runs, lowered
+        for ``state`` (``.compile().as_text()`` shows which kernels it
+        holds); the arrays are not consumed."""
+        return self._chunk(None).lower(state)
+
+    def _chunk(self, length: Optional[int]) -> Callable:
         length = self.scan_chunk if length is None else length
         cache = getattr(self, "_chunks", None)
         if cache is None:
@@ -117,7 +125,7 @@ class Executor:
         fn = cache.get(length)
         if fn is None:
             fn = cache[length] = self._build_chunk(length)
-        return fn(state)
+        return fn
 
     def run(self, state: LoopState, iterations: int, log_every: int = 0
             ) -> Tuple[LoopState, Dict[str, jax.Array]]:
@@ -199,6 +207,8 @@ class FusedExecutor(Executor):
 
         def run(state: LoopState):
             return fn(state.replay, state._replace(replay=()))
+        run.lower = lambda state: fn.lower(state.replay,
+                                           state._replace(replay=()))
         return run
 
     def init(self, key: jax.Array) -> LoopState:
@@ -374,9 +384,9 @@ class ShardedExecutor(Executor):
                                  overlap=overlap_pod_reduce)
             return self._global_state(st)
 
-        self._init = jax.jit(shard_map(
+        self._init = jax.jit(jax.shard_map(
             init_local, mesh=mesh, in_specs=(PartitionSpec(),),
-            out_specs=self._specs, check_rep=False))
+            out_specs=self._specs, check_vma=False))
 
     def _reduce_metrics(self, metrics: Dict[str, jax.Array]
                         ) -> Dict[str, jax.Array]:
@@ -419,14 +429,16 @@ class ShardedExecutor(Executor):
 
         # replay (tree + storage) donated at the jit boundary, same as
         # the fused path — per-shard buffers alias through shard_map
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             chunk_local, mesh=self.mesh,
             in_specs=(self._specs.replay, self._specs._replace(replay=())),
-            out_specs=(self._specs, self._metric_specs), check_rep=False),
+            out_specs=(self._specs, self._metric_specs), check_vma=False),
             donate_argnums=(0,))
 
         def run(state: LoopState):
             return fn(state.replay, state._replace(replay=()))
+        run.lower = lambda state: fn.lower(state.replay,
+                                           state._replace(replay=()))
         return run
 
     # -- per-shard ↔ global state layout ----------------------------------

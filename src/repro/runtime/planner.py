@@ -569,7 +569,7 @@ def _load_points(path: str) -> List[dict]:
 _MEASUREMENT_FIELDS = frozenset({
     "env_steps_per_s", "inserts_per_s", "samples_per_s",
     "replay_ops_per_s", "speedup_vs_sync", "repeats", "rel_spread",
-    "realized_spi",
+    "realized_spi", "platform",
     # actor-serve figure (benchmarks/fig_actor.py) measurements
     "requests_per_s", "p50_ms", "p99_ms",
     "p99_before_swap_ms", "p99_after_swap_ms", "param_swaps",

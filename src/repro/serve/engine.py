@@ -56,14 +56,6 @@ class DecodeState(NamedTuple):
     active: jax.Array             # (slots,) bool
 
 
-def _cache_size(fn) -> int:
-    """Retrace counter: how many signatures this jit has compiled."""
-    try:
-        return int(fn._cache_size())
-    except AttributeError:  # pragma: no cover — older/newer jax fallback
-        return -1
-
-
 class DecodeEngine:
     def __init__(self, cfg: ModelConfig, shd=NO_SHARDING, *, slots: int,
                  max_len: int, buckets: BucketSpec):
@@ -183,8 +175,8 @@ class DecodeEngine:
     @property
     def prime_compiles(self) -> int:
         """Bounded by ``len(buckets.edges)`` — the §13 retrace invariant."""
-        return _cache_size(self._prime)
+        return int(self._prime._cache_size())
 
     @property
     def decode_compiles(self) -> int:
-        return _cache_size(self._step)
+        return int(self._step._cache_size())

@@ -9,6 +9,7 @@ against the real gloo transport.
 """
 
 import functools
+import os
 import subprocess
 import sys
 import time
@@ -25,6 +26,20 @@ def test_parse_kv_takes_upper_snake_lines_later_wins():
             "STEPS_PER_S=13.0\nREL_SPREAD=0.01\n")
     kv = mp.parse_kv(text)
     assert kv == {"STEPS_PER_S": "13.0", "REL_SPREAD": "0.01"}
+
+
+def test_worker_env_pins_only_cpu_roles(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    cpu = mp.worker_env(2, "cpu")
+    assert cpu["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=2" in cpu["XLA_FLAGS"]
+    # a role on the default platform inherits the caller's choice; this
+    # process runs JAX on the CPU, so it holds no chip a child would need
+    default = mp.worker_env(1, None)
+    assert default["JAX_PLATFORMS"] == "tpu"
+    assert "XLA_FLAGS" not in default
+    assert default["PYTHONPATH"].split(os.pathsep)[0].endswith("src")
 
 
 def test_launch_rejects_empty_gang():

@@ -549,6 +549,8 @@ def test_quickstart_trains_from_plan_json(tmp_path):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)            # quickstart sets the device flag
+    # keep the entry point's compile cache out of the checkout
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "jax_cache")
     env["PYTHONPATH"] = (f"{os.path.join(root, 'src')}:"
                          f"{env.get('PYTHONPATH', '')}").rstrip(":")
     r = subprocess.run(

@@ -40,6 +40,9 @@ def test_service_gang_trains_cartpole():
 
     server, learner = res["server"], res["learner"]
     _assert_spi_band(server)
+    # every role names its platform; the host roles are pinned to the CPU
+    # and the learner takes the default platform (the CPU here)
+    assert {kv["PLATFORM"] for kv in res.values()} == {"cpu"}
     # counters agree across the boundary: the server's limiter totals are
     # what the learner saw in its final stats round trip
     assert server["INSERTS"] == learner["SERVICE_INSERTS"]

@@ -15,11 +15,9 @@ SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from repro.core.distributed import ShardedPrioritizedReplay, ShardedReplayConfig
-    from repro.launch.mesh import use_mesh
 
     assert jax.device_count() == 8
     mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
@@ -50,27 +48,27 @@ SCRIPT = textwrap.dedent("""
     state_shapes = jax.eval_shape(init_fn)
     state_specs = specs_like(state_shapes)
 
-    with use_mesh(mesh):
-        sm_init = shard_map(init_fn, mesh=mesh, in_specs=(),
-                            out_specs=state_specs, check_rep=False)
+    with jax.set_mesh(mesh):
+        sm_init = jax.shard_map(init_fn, mesh=mesh, in_specs=(),
+                            out_specs=state_specs, check_vma=False)
         state = sm_init()
         # per-shard distinct rewards so shards are distinguishable
         items = {
             "obs": jnp.arange(8 * 32 * 3, dtype=jnp.float32).reshape(8 * 32, 3),
             "reward": jnp.repeat(jnp.arange(8, dtype=jnp.float32), 32),
         }
-        sm_insert = shard_map(insert_fn, mesh=mesh,
+        sm_insert = jax.shard_map(insert_fn, mesh=mesh,
                               in_specs=(state_specs, P("data")),
-                              out_specs=state_specs, check_rep=False)
+                              out_specs=state_specs, check_vma=False)
         state = sm_insert(state, items)
         assert int(state.count) == 32  # per-shard count (replicated scalar)
 
         rngs = jax.random.split(jax.random.PRNGKey(0), 8)
-        sm_sample = shard_map(sample_fn, mesh=mesh,
+        sm_sample = jax.shard_map(sample_fn, mesh=mesh,
                               in_specs=(state_specs, P("data")),
                               out_specs=(P("data"), P("data"), P("data"),
                                          P("data"), P(), P()),
-                              check_rep=False)
+                              check_vma=False)
         idx, got, w, pri, g_tot, g_cnt = sm_sample(state, rngs)
         # global stats from the psum: full global count across all shards
         np.testing.assert_allclose(float(g_cnt), 256.0)
@@ -113,11 +111,10 @@ TWO_AXIS_SCRIPT = textwrap.dedent("""
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.distributed import ShardedPrioritizedReplay, ShardedReplayConfig
-    from repro.launch.mesh import pod_data_mesh, use_mesh
+    from repro.launch.mesh import pod_data_mesh
 
     assert jax.device_count() == 4
     mesh = pod_data_mesh(2, 2)
@@ -146,9 +143,9 @@ TWO_AXIS_SCRIPT = textwrap.dedent("""
 
     state_specs = specs_like(jax.eval_shape(init_fn))
 
-    with use_mesh(mesh):
-        state = shard_map(init_fn, mesh=mesh, in_specs=(),
-                          out_specs=state_specs, check_rep=False)()
+    with jax.set_mesh(mesh):
+        state = jax.shard_map(init_fn, mesh=mesh, in_specs=(),
+                          out_specs=state_specs, check_vma=False)()
         # per-mesh-cell distinct rewards (flattened shard id 0..3) with
         # distinct priority masses per cell, so the global stats are a
         # nontrivial sum over BOTH axes
@@ -156,24 +153,24 @@ TWO_AXIS_SCRIPT = textwrap.dedent("""
             "obs": jnp.arange(4 * 32 * 3, dtype=jnp.float32).reshape(4 * 32, 3),
             "reward": jnp.repeat(jnp.arange(4, dtype=jnp.float32), 32),
         }
-        state = shard_map(insert_fn, mesh=mesh,
+        state = jax.shard_map(insert_fn, mesh=mesh,
                           in_specs=(state_specs, P(axes)),
-                          out_specs=state_specs, check_rep=False)(state, items)
+                          out_specs=state_specs, check_vma=False)(state, items)
         # skew cell 3's priorities upward so the global max normalizer
         # provably comes from a different cell than 0..2 sample locally
         def skew_fn(state):
             sid = jax.lax.axis_index("pod") * 2 + jax.lax.axis_index("data")
             pri = jnp.where(sid == 3, 9.0, 1.0) * jnp.ones((32,))
             return rb.update_priorities(state, jnp.arange(32), pri)
-        state = shard_map(skew_fn, mesh=mesh, in_specs=(state_specs,),
-                          out_specs=state_specs, check_rep=False)(state)
+        state = jax.shard_map(skew_fn, mesh=mesh, in_specs=(state_specs,),
+                          out_specs=state_specs, check_vma=False)(state)
 
         rngs = jax.random.split(jax.random.PRNGKey(0), 4)
-        idx, got, w, pri, g_tot, g_cnt = shard_map(
+        idx, got, w, pri, g_tot, g_cnt = jax.shard_map(
             sample_fn, mesh=mesh,
             in_specs=(state_specs, P(axes)),
             out_specs=(P(axes), P(axes), P(axes), P(axes), P(), P()),
-            check_rep=False)(state, rngs)
+            check_vma=False)(state, rngs)
 
         # global stats psum over BOTH axes: all 4 cells' counts/totals
         np.testing.assert_allclose(float(g_cnt), 128.0)
