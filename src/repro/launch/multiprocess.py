@@ -42,8 +42,8 @@ one of three workloads:
     the shift-identity and telescoping errors
     (tests/test_distributed.py).
 
-Chunks are bracketed with ``jax.profiler.StepTraceAnnotation`` step
-markers so profile traces segment per chunk.
+Profile traces segment per chunk by the executor's own step marker
+(``Executor.run_chunk``'s ``run_chunk`` span).
 
 **Replay-service gang** (DESIGN.md §11): ``launch_service`` spawns a
 second kind of gang — one ``--mode replay-server`` process hosting the
@@ -520,13 +520,11 @@ def _bench_worker(args):
     pid = jax.process_index()
     publish = args.publish_interval
 
-    def run_iters(state, iters, base_step):
+    def run_iters(state, iters):
         done = 0
         while done < iters:
             length = min(publish or ex.scan_chunk, iters - done)
-            with jax.profiler.StepTraceAnnotation(
-                    "wallclock_chunk", step_num=base_step + done):
-                state, metrics = ex.run_chunk(state, length)
+            state, metrics = ex.run_chunk(state, length)
             if publish:
                 state = _publish_host_roundtrip(ex, state)
             done += length
@@ -534,11 +532,11 @@ def _bench_worker(args):
 
     state = ex.init(jax.random.PRNGKey(args.seed))
     # warmup compiles every chunk length the timed loop will use
-    state, _ = run_iters(state, args.iters, 0)
+    state, _ = run_iters(state, args.iters)
     samples = []
     for r in range(args.repeats):
         t0 = time.perf_counter()
-        state, metrics = run_iters(state, args.iters, (r + 1) * args.iters)
+        state, metrics = run_iters(state, args.iters)
         jax.block_until_ready(metrics["env_steps"])
         dt = time.perf_counter() - t0
         samples.append(args.n_envs * args.iters / dt)

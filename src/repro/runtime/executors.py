@@ -82,6 +82,7 @@ from jax.sharding import Mesh, PartitionSpec
 from repro.agents.base import Agent
 from repro.core.distributed import ShardedPrioritizedReplay
 from repro.core.replay import PrioritizedReplay
+from repro.runtime import phases
 from repro.runtime.learner import make_sharded_learn
 from repro.runtime.loop import (METRIC_KEYS, LoopConfig, LoopState,
                                 RatioSchedule, init_loop_state, make_step)
@@ -95,6 +96,7 @@ class Executor:
 
     schedule: RatioSchedule
     scan_chunk: int
+    chunks_dispatched: int = 0    # run_chunk calls, the span's step_num
 
     def init(self, key: jax.Array) -> LoopState:
         raise NotImplementedError
@@ -108,14 +110,30 @@ class Executor:
         """(state) → (state, per-iteration metrics of shape (length,)).
 
         Compiled programs are cached per distinct ``length`` — the run
-        loop only ever uses ``scan_chunk`` plus one tail length."""
-        return self._chunk(length)(state)
+        loop only ever uses ``scan_chunk`` plus one tail length.  The
+        host's dispatch is one profiler step, ``run_chunk`` with
+        ``step_num`` (chunks this executor dispatched before) and
+        ``length`` (iterations): the program's single chunk marker."""
+        length = self.scan_chunk if length is None else length
+        step_num = self.chunks_dispatched
+        self.chunks_dispatched = step_num + 1
+        with jax.profiler.StepTraceAnnotation("run_chunk", step_num=step_num,
+                                              length=length):
+            return self._chunk(length)(state)
 
     def lower_chunk(self, state: LoopState):
         """The ``scan_chunk``-long program ``run_chunk`` runs, lowered
         for ``state`` (``.compile().as_text()`` shows which kernels it
         holds); the arrays are not consumed."""
         return self._chunk(None).lower(state)
+
+    def op_phases(self, state: LoopState) -> Dict[str, str]:
+        """``{HLO instruction name: phase}`` of the compiled chunk that
+        ``run_chunk`` runs for ``state`` (arrays or ShapeDtypeStructs;
+        nothing is consumed).  A profile names each device op by its
+        instruction, so this joins a profile's op times to the step's
+        phases (runtime/phases.py); ops it lacks belong to no phase."""
+        return phases.op_phases(self.lower_chunk(state).compile().as_text())
 
     def _chunk(self, length: Optional[int]) -> Callable:
         length = self.scan_chunk if length is None else length

@@ -316,32 +316,45 @@ def make_sharded_learn(
                                      overlap=overlap)
 
     def sharded_learn(agent_state, replay_state, rng, age=None, ef=None):
-        idx, items, is_w = replay.sample(replay_state, rng, batch_per_shard, beta)
+        # phase scopes as in loop.make_learner_step (runtime/phases.py)
+        with jax.named_scope("sample"):
+            idx, items, is_w = replay.sample(replay_state, rng,
+                                             batch_per_shard, beta)
         err_norm = jnp.zeros(())
         if agent.grads is not None and agent.apply_grads is not None:
-            grads, aux = agent.grads(agent_state, items, is_w)
-            if cast_active:
-                # compression error this shard injects into the fast leg
-                err_norm = err_norm + compress.l2_norm(jax.tree.map(
-                    lambda g: g - g.astype(wire_dtype).astype(g.dtype),
-                    grads))
-            grads, ef = reduce_grads(grads, age, ef)
-            if jax.tree.leaves(ef):
-                # residual the int8 pod leg carries into the next step
-                # (overlap mode also carries the stale correction — only
-                # the quantizer's EF half is compression error)
-                err_norm = err_norm + compress.l2_norm(
-                    ef["ef"] if overlap else ef)
-            agent_state, metrics, td = agent.apply_grads(agent_state, grads, aux)
+            with jax.named_scope("learner_update"):
+                grads, aux = agent.grads(agent_state, items, is_w)
+                if cast_active:
+                    # compression error this shard injects into the fast
+                    # leg
+                    err_norm = err_norm + compress.l2_norm(jax.tree.map(
+                        lambda g: g - g.astype(wire_dtype).astype(g.dtype),
+                        grads))
+            with jax.named_scope("grad_reduce"):
+                grads, ef = reduce_grads(grads, age, ef)
+                if jax.tree.leaves(ef):
+                    # residual the int8 pod leg carries into the next
+                    # step (overlap mode also carries the stale
+                    # correction — only the quantizer's EF half is
+                    # compression error)
+                    err_norm = err_norm + compress.l2_norm(
+                        ef["ef"] if overlap else ef)
+            with jax.named_scope("learner_update"):
+                agent_state, metrics, td = agent.apply_grads(agent_state,
+                                                             grads, aux)
         else:
-            agent_state, metrics, td = agent.learn(agent_state, items, is_w)
-            agent_state = agent_state._replace(
-                params=_pmean_inexact(agent_state.params, axes),
-                target=_pmean_inexact(agent_state.target, axes),
-                opt=_pmean_inexact(agent_state.opt, axes),
-            )
-        replay_state = replay.update_priorities(replay_state, idx, td,
-                                                lazy=lazy_writes)
+            with jax.named_scope("learner_update"):
+                agent_state, metrics, td = agent.learn(agent_state, items,
+                                                       is_w)
+            with jax.named_scope("grad_reduce"):
+                agent_state = agent_state._replace(
+                    params=_pmean_inexact(agent_state.params, axes),
+                    target=_pmean_inexact(agent_state.target, axes),
+                    opt=_pmean_inexact(agent_state.opt, axes),
+                )
+        with jax.named_scope("write_back"):
+            replay_state = replay.update_priorities(replay_state, idx, td,
+                                                    lazy=lazy_writes)
         lmetrics = {"loss": metrics["loss"], "compress_error_norm": err_norm}
         return agent_state, replay_state, lmetrics, ef
 

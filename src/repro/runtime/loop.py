@@ -263,10 +263,15 @@ def make_learner_step(agent: Agent, replay, cfg: LoopConfig):
 
     def learner_step(agent_state, replay_state, rng, age=None, ef=None):
         del age  # fused learner: no cross-shard reduce to weight
-        idx, items, is_w = replay.sample(replay_state, rng, cfg.batch_size, cfg.beta)
-        agent_state, metrics, td = agent.learn(agent_state, items, is_w)
-        replay_state = replay.update_priorities(replay_state, idx, td,
-                                                lazy=cfg.lazy_replay)
+        # phase scopes (runtime/phases.py): HLO metadata only
+        with jax.named_scope("sample"):
+            idx, items, is_w = replay.sample(replay_state, rng,
+                                             cfg.batch_size, cfg.beta)
+        with jax.named_scope("learner_update"):
+            agent_state, metrics, td = agent.learn(agent_state, items, is_w)
+        with jax.named_scope("write_back"):
+            replay_state = replay.update_priorities(replay_state, idx, td,
+                                                    lazy=cfg.lazy_replay)
         lmetrics = {"loss": metrics["loss"],
                     "compress_error_norm": jnp.zeros(())}
         return agent_state, replay_state, lmetrics, ef
@@ -335,25 +340,30 @@ def make_step(
     sum_across = sum_across or (lambda x: x)
 
     def step(state: LoopState) -> Tuple[LoopState, Dict[str, jax.Array]]:
-        rng_next, k = jax.random.split(state.rng)
-        sid = shard_id() if callable(shard_id) else shard_id
-        k = jax.random.fold_in(k, sid)
-        k_act, k_env, k_sample = jax.random.split(k, 3)
+        # each numbered step runs under its phase's named scope
+        # (runtime/phases.py): HLO metadata only, the ops are unchanged
 
         # 1. parallel actors — on the delayed double-buffered copy when
         #    async, on the fresh learner params when synchronous
-        acting = (agent.with_acting_params(state.agent, state.actor_params)
-                  if publish_interval else state.agent)
-        eps = epsilon_schedule(cfg, state.env_steps)
-        env_state, obs_next, ep_ret, last_ret, transitions = actor_step(
-            acting, state.env_state, state.obs,
-            state.episode_return, state.last_return, k_act, k_env, eps)
+        with jax.named_scope("act"):
+            rng_next, k = jax.random.split(state.rng)
+            sid = shard_id() if callable(shard_id) else shard_id
+            k = jax.random.fold_in(k, sid)
+            k_act, k_env, k_sample = jax.random.split(k, 3)
+            acting = (agent.with_acting_params(state.agent,
+                                               state.actor_params)
+                      if publish_interval else state.agent)
+            eps = epsilon_schedule(cfg, state.env_steps)
+            env_state, obs_next, ep_ret, last_ret, transitions = actor_step(
+                acting, state.env_state, state.obs,
+                state.episode_return, state.last_return, k_act, k_env, eps)
 
         # 2. lazy write, phase 1: zero the in-flight slots' leaf
         #    priorities (propagation deferred to the flush below)
         lazy = cfg.lazy_replay
-        replay_state, slots = replay.insert_begin(state.replay, n_envs,
-                                                  lazy=lazy)
+        with jax.named_scope("insert_begin"):
+            replay_state, slots = replay.insert_begin(state.replay, n_envs,
+                                                      lazy=lazy)
 
         # 3. THE flush boundary: one merged upward-propagation pass per
         #    iteration, coalescing the previous iteration's priority
@@ -361,14 +371,11 @@ def make_step(
         #    After this the tree is consistent and the in-flight slots
         #    are unsampleable (lazy ≡ eager bit-exact at this point).
         if lazy:
-            replay_state = replay.flush(replay_state)
+            with jax.named_scope("flush"):
+                replay_state = replay.flush(replay_state)
 
         # 4. parallel learners on the flushed tree state, at the scheduled
         #    collection/consumption ratio — always on the fresh params
-        it = state.env_steps // schedule.env_steps_per_iter
-        can_learn = (state.env_steps >= cfg.warmup) & (it % schedule.period == 0)
-        age = state.params_age if publish_interval else jnp.zeros((), jnp.int32)
-
         def do_learn(args):
             agent_state, rstate, ef = args
             acc = {k: jnp.zeros(()) for k in LEARN_METRIC_KEYS}
@@ -377,7 +384,8 @@ def make_step(
                     # extra learner calls in the same event must also
                     # sample a consistent tree: flush the previous
                     # call's priority write-back first
-                    rstate = replay.flush(rstate)
+                    with jax.named_scope("flush"):
+                        rstate = replay.flush(rstate)
                 ki = jax.random.fold_in(k_sample, i)
                 agent_state, rstate, lmetrics, ef = learn_fn(
                     agent_state, rstate, ki, age=age, ef=ef)
@@ -391,28 +399,39 @@ def make_step(
             zeros = {k: jnp.zeros(()) for k in LEARN_METRIC_KEYS}
             return agent_state, rstate, zeros, state.learn_steps, ef
 
-        agent_state, replay_state, lmetrics, learn_steps, ef_error = jax.lax.cond(
-            can_learn, do_learn, skip_learn,
-            (state.agent, replay_state, state.ef_error))
+        with jax.named_scope("learn"):
+            it = state.env_steps // schedule.env_steps_per_iter
+            can_learn = ((state.env_steps >= cfg.warmup)
+                         & (it % schedule.period == 0))
+            age = (state.params_age if publish_interval
+                   else jnp.zeros((), jnp.int32))
+            (agent_state, replay_state, lmetrics, learn_steps,
+             ef_error) = jax.lax.cond(
+                can_learn, do_learn, skip_learn,
+                (state.agent, replay_state, state.ef_error))
 
         # 6. lazy write, phase 3: storage write + P_max restore (the
         #    leaf write is eager, its propagation rides the next flush)
-        replay_state = replay.insert_commit(replay_state, slots, transitions,
-                                            lazy=lazy)
+        with jax.named_scope("insert_commit"):
+            replay_state = replay.insert_commit(replay_state, slots,
+                                                transitions, lazy=lazy)
 
         # 7. async publish: refresh this shard's acting copy from the
         #    fresh learner params on its (staggered) publish tick —
         #    unless the host runtime owns the publish (wall-clock mode:
         #    real D2H transfer between chunks, age just keeps counting)
-        if publish_interval and external_publish:
-            actor_params = state.actor_params
-            params_age = state.params_age + 1
-        elif publish_interval:
-            publish = (it + 1 + sid) % publish_interval == 0
-            actor_params = jax.tree.map(
-                lambda fresh, held: jnp.where(publish, fresh, held),
-                agent.params_for_acting(agent_state), state.actor_params)
-            params_age = jnp.where(publish, 0, state.params_age + 1)
+        if publish_interval:
+            with jax.named_scope("publish"):
+                if external_publish:
+                    actor_params = state.actor_params
+                    params_age = state.params_age + 1
+                else:
+                    publish = (it + 1 + sid) % publish_interval == 0
+                    actor_params = jax.tree.map(
+                        lambda fresh, held: jnp.where(publish, fresh, held),
+                        agent.params_for_acting(agent_state),
+                        state.actor_params)
+                    params_age = jnp.where(publish, 0, state.params_age + 1)
         else:
             actor_params, params_age = state.actor_params, state.params_age
 
