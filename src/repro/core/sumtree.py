@@ -273,6 +273,15 @@ def sample(
     Per level, reads exactly one K-aligned sibling row per sample and
     finds the cutoff node (Theorem 2) with a vectorized cumsum+argmax —
     the lane-parallel analogue of the paper's linear child scan.
+
+    A descent step is one row gather from the level viewed as a
+    ``(groups, K)`` matrix (a static slice of the flat tree, exact
+    because sibling groups are K-aligned).  A 1-D ``dynamic_slice``
+    per draw under ``vmap`` would compute the same rows, but XLA lowers
+    its arbitrary start offsets to a serial loop over the draws per
+    level on TPU.  A group past the level's end (the no-hit clamp can
+    pick a padding node) reads the last row; such a draw ends beyond
+    the capacity and takes the final leaf clamp either way.
     """
     u = jnp.asarray(u, tree.dtype)
     residual = jnp.clip(u, 1e-12, 1.0 - 1e-7) * tree[0]
@@ -280,12 +289,9 @@ def sample(
     k = spec.fanout
 
     for level in range(1, spec.leaf_level + 1):
-        base = spec.offsets[level] + group * k
-
-        def read_row(b):
-            return jax.lax.dynamic_slice(tree, (b,), (k,))
-
-        rows = jax.vmap(read_row)(base)            # (B, K) sibling rows
+        off, size = spec.offsets[level], spec.level_sizes[level]
+        view = tree[off:off + size].reshape(size // k, k)
+        rows = jnp.take(view, group, axis=0, mode="clip")  # (B, K) sibling rows
         csum = jnp.cumsum(rows, axis=-1)           # lane-parallel scan
         hit = csum >= residual[:, None]
         cutoff = jnp.argmax(hit, axis=-1).astype(jnp.int32)

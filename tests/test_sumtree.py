@@ -2,6 +2,8 @@
 semantics (last-writer-wins), sampling distribution — incl. hypothesis
 property tests over capacities/fanouts/priorities."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -148,3 +150,57 @@ def test_add_accumulates_duplicates():
     leaves = np.asarray(sumtree.leaves(spec, tree))
     assert leaves[5] == 3.0 and leaves[9] == 1.0
     assert float(tree[0]) == 4.0
+
+
+def _sample_flat_slices(spec, tree, u):
+    """The descent as it read sibling rows before the row gather: one 1-D
+    ``dynamic_slice`` of the flat tree per draw under ``vmap`` (a serial
+    loop over the draws on TPU).  Kept as the reference for the rows."""
+    u = jnp.asarray(u, tree.dtype)
+    residual = jnp.clip(u, 1e-12, 1.0 - 1e-7) * tree[0]
+    group = jnp.zeros(u.shape, jnp.int32)  # start: children of root = group 0
+    k = spec.fanout
+
+    for level in range(1, spec.leaf_level + 1):
+        base = spec.offsets[level] + group * k
+
+        def read_row(b):
+            return jax.lax.dynamic_slice(tree, (b,), (k,))
+
+        rows = jax.vmap(read_row)(base)            # (B, K) sibling rows
+        csum = jnp.cumsum(rows, axis=-1)           # lane-parallel scan
+        hit = csum >= residual[:, None]
+        cutoff = jnp.argmax(hit, axis=-1).astype(jnp.int32)
+        # No-hit (fp rounding at the tail): clamp to last child.
+        cutoff = jnp.where(jnp.any(hit, axis=-1), cutoff, k - 1)
+        picked = jnp.take_along_axis(csum, cutoff[:, None], axis=-1)[:, 0]
+        row_val = jnp.take_along_axis(rows, cutoff[:, None], axis=-1)[:, 0]
+        residual = residual - (picked - row_val)   # subtract prefix before cutoff
+        group = group * k + cutoff
+
+    leaf = jnp.minimum(group, spec.capacity - 1)
+    return leaf, tree[spec.leaf_offset + leaf]
+
+
+@pytest.mark.parametrize("root_scale", [1.0, 1.01], ids=["exact", "stale_root"])
+@pytest.mark.parametrize("capacity,fanout", [
+    (1000, 8), (16389, 128), (70000, 128), (2 ** 20, 128),
+])
+def test_row_gather_descent_matches_flat_slices(capacity, fanout, root_scale):
+    """The row-gather descent returns exactly the leaves and priorities
+    of the per-draw flat-slice descent, tail draws included.  A root
+    above its children's sum sends the top draws through the no-hit
+    clamp onto padding nodes, past the end of the level below."""
+    spec = sumtree.make_spec(capacity, fanout)
+    rng = np.random.default_rng(capacity)
+    pri = rng.uniform(0.0, 2.0, capacity).astype(np.float32)
+    pri[rng.random(capacity) < 0.3] = 0.0
+    tree = sumtree.build(spec, jnp.asarray(pri))
+    tree = tree.at[0].multiply(root_scale)
+    tail = np.array([0.0, 1e-9, 1.0 - 1e-7, 1.0], np.float32)
+    u = jnp.asarray(np.concatenate(
+        [rng.uniform(0, 1, 256).astype(np.float32), tail]))
+    new = jax.jit(functools.partial(sumtree.sample, spec))(tree, u)
+    old = jax.jit(functools.partial(_sample_flat_slices, spec))(tree, u)
+    np.testing.assert_array_equal(np.asarray(new[0]), np.asarray(old[0]))
+    np.testing.assert_array_equal(np.asarray(new[1]), np.asarray(old[1]))

@@ -6,7 +6,9 @@ for a ``v5e:2x2`` chip that is described, not attached, and compiled by
 the TPU compiler installed alongside JAX.  Nothing runs.  Sizes are the
 ones the chip smoke test drives: a 2^20-leaf, fanout-128 sum tree with a
 256-draw batch (two 128-row blocks, so block tiling is exercised), and
-flash attention at 32 heads × 2048 tokens × head dim 128 in bf16.
+flash attention at 32 heads × 2048 tokens × head dim 128 in bf16.  The
+XLA backend's sample+gather is compiled at the same tree size to check
+that its descent holds no serial loop over the draws.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every xdist worker imports this
@@ -22,6 +24,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.sumtree import make_spec
+from repro.core.tree_ops import XlaTreeOps
 from repro.kernels import flash_attention as FA
 from repro.kernels import gather as _gather
 from repro.kernels import ops as kops
@@ -104,6 +107,20 @@ def test_gather_compiles(one_chip):
     fn = functools.partial(_gather.gather_rows, interpret=False)
     _assert_kernel(fn, _sds((CAPACITY, 4), jnp.float32, one_chip),
                    _sds((BATCH,), jnp.int32, one_chip))
+
+
+@pytest.mark.parametrize("batch", [64, 256])
+def test_xla_sample_gather_has_no_loop(one_chip, batch):
+    """The XLA backend's descent reads each level's sibling rows with one
+    row gather: the compiled program holds no serial loop over the draws."""
+    spec = make_spec(CAPACITY, FANOUT)
+    storage = tuple(_sds((CAPACITY, f), jnp.float32, one_chip)
+                    for f in STORAGE_WIDTHS)
+    fn = functools.partial(XlaTreeOps().sample_gather, spec)
+    compiled = jax.jit(fn).lower(
+        _sds((spec.total_size,), jnp.float32, one_chip),
+        _sds((batch,), jnp.float32, one_chip), storage).compile()
+    assert "while(" not in compiled.as_text()
 
 
 def test_kernel_tree_fits_budget():
