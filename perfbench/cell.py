@@ -1,20 +1,20 @@
 """The general generator and runner of a training cell.
 
-A cell is one configuration (agent, env, replay sizes) under one
-traffic mix (actors, batch, collection/learning ratio, tree backend,
-mesh).  ``run`` builds the executor the user would build, fills the
-replay to capacity through the replay's own insert path with the env
-under a uniformly random policy, compiles, and drives the first chunk
-through the executor's own ``run_chunk``: that chunk is the warm-up and
-the probe that the comparison with the plain reference reads.  The same
-executor and state then run the measured window (``--trace 0``) or a
-short profiled window (``--trace 1``).
+A cell is one configuration (its algorithm family's module in
+``perfbench/algorithms/`` makes the agent, env and row; replay sizes)
+under one traffic mix (actors, batch, collection/learning ratio, tree
+backend, mesh).  ``run`` builds the executor the user would build,
+fills the replay to capacity through the replay's own insert path with
+the family's env under a uniformly random policy, compiles, and drives
+the first chunk through the executor's own ``run_chunk``: that chunk is
+the warm-up and the probe that the comparison with the plain reference
+reads.  The same executor and state then run the measured window
+(``--trace 0``) or a short profiled window (``--trace 1``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import gc
 import math
 import shutil
@@ -25,7 +25,7 @@ from typing import Dict, Optional
 
 import numpy as np
 
-from perfbench import check, reference, trace_reduce, work
+from perfbench import algorithms, check, reference, trace_reduce, work
 from perfbench.peaks import peaks_for
 from perfbench.spec import Cell
 
@@ -59,8 +59,6 @@ class CompileClock:
 class Built:
     executor: object
     replay: object
-    example: dict
-    env_spec: object
     n_shards: int
     capacity: int           # rows per shard
     n_envs: int             # global actors
@@ -72,46 +70,15 @@ class Built:
 
 
 def build(cell: Cell) -> Built:
-    import jax.numpy as jnp
-
     from repro.core.replay import PrioritizedReplay, ReplayConfig
-    from repro.envs.classic import make_vec
     from repro.optim.adam import AdamConfig
     from repro.runtime.executors import FusedExecutor, ShardedExecutor
     from repro.runtime.loop import LoopConfig
 
     c, t = cell.config, cell.traffic
-    env_fn = functools.partial(make_vec, c["env"])
-    spec, _, _ = env_fn(1)
     opt = AdamConfig(lr=c["learning_rate"], b1=c["adam_b1"], b2=c["adam_b2"],
                      eps=c["adam_eps"], grad_clip=c["grad_clip_norm"])
-    hidden = tuple(c["hidden_sizes"])
-    if c["algorithm"] == "ddpg":
-        from repro.agents.ddpg import DDPGConfig, make_ddpg
-
-        if (spec.obs_dim, spec.action_dim, spec.action_low,
-                spec.action_high) != (c["obs_dim"], c["action_dim"],
-                                      c["action_low"], c["action_high"]):
-            raise ValueError(f"env {c['env']} does not match the config")
-        agent = make_ddpg(spec, DDPGConfig(hidden=hidden, gamma=c["gamma"],
-                                           tau=c["tau"],
-                                           expl_noise=c["expl_noise"],
-                                           opt=opt))
-        action = jnp.zeros((c["action_dim"],), jnp.float32)
-    else:
-        from repro.agents.dqn import DQNConfig, make_dqn
-
-        if (spec.obs_dim, spec.action_dim) != (c["obs_dim"], c["num_actions"]):
-            raise ValueError(f"env {c['env']} does not match the config")
-        agent = make_dqn(spec, DQNConfig(hidden=hidden, gamma=c["gamma"],
-                                         tau=c["tau"],
-                                         double_q=c["double_q"], opt=opt))
-        action = jnp.zeros((), jnp.int32)
-    example = {"obs": jnp.zeros((spec.obs_dim,), jnp.float32),
-               "action": action,
-               "reward": jnp.zeros((), jnp.float32),
-               "next_obs": jnp.zeros((spec.obs_dim,), jnp.float32),
-               "done": jnp.zeros((), jnp.float32)}
+    agent, env_fn, example = algorithms.of(c).build(c, t, opt)
     loop = LoopConfig(batch_size=t["batch_size"],
                       update_interval=t["update_interval"], warmup=0,
                       epsilon=t["epsilon"], epsilon_final=t["epsilon_final"],
@@ -143,54 +110,38 @@ def build(cell: Cell) -> Built:
         ex = ShardedExecutor(agent, replay, env_fn, loop, t["n_envs"], mesh,
                              scan_chunk=t["scan_chunk"])
         n_shards = math.prod(shape)
-    return Built(executor=ex, replay=replay, example=example, env_spec=spec,
-                 n_shards=n_shards, capacity=cap, n_envs=t["n_envs"],
-                 batch=t["batch_size"], learns=ex.schedule.learns, mesh=mesh)
+    return Built(executor=ex, replay=replay, n_shards=n_shards, capacity=cap,
+                 n_envs=t["n_envs"], batch=t["batch_size"],
+                 learns=ex.schedule.learns, mesh=mesh)
 
 
 def make_fill(b: Built, cell: Cell):
     """Jitted ``fill(key, replay_state) → (replay_state, rows)``: every
-    shard's buffer gets ``capacity`` transitions of the config's env
-    under a uniformly random policy, appended through the replay's own
-    writer transaction and flushed once.  ``rows`` is that input, as the
+    shard's buffer gets ``capacity`` rows of the family's fill (a
+    uniformly random policy), appended through the replay's own writer
+    transaction and flushed once.  ``rows`` is that input, as the
     reference reads it."""
     import jax
     import jax.numpy as jnp
-
-    from repro.envs.classic import make_vec
 
     n = cell.traffic["fill_envs"]
     cap = b.capacity
     if cap % n:
         raise ValueError(f"capacity {cap} is not a multiple of fill_envs {n}")
-    spec = b.env_spec
-    _, v_reset, v_step = make_vec(cell.config["env"], n)
-
-    def actions(k):
-        if spec.discrete:
-            return jax.random.randint(k, (n,), 0, spec.action_dim)
-        return jax.random.uniform(k, (n, spec.action_dim),
-                                  minval=spec.action_low,
-                                  maxval=spec.action_high)
+    start, step = algorithms.of(cell.config).fill(cell.config, n)
 
     def fill_local(key, rs):
         k0, k1 = jax.random.split(key)
-        st, obs = v_reset(k0)
 
         def body(carry, k):
-            st, obs, rs = carry
-            ka, ke = jax.random.split(k)
-            a = actions(ka)
-            st, obs_next, rew, done, true_next = v_step(st, a, ke)
-            tr = {"obs": obs, "action": a.astype(b.example["action"].dtype),
-                  "reward": rew.astype(jnp.float32), "next_obs": true_next,
-                  "done": done.astype(jnp.float32)}
-            rs = b.replay.append(rs, tr, lazy=True)
-            return (st, obs_next, rs), tr
+            carry, rs = carry
+            carry, row = step(carry, k)
+            rs = b.replay.append(rs, row, lazy=True)
+            return (carry, rs), row
 
-        (_, _, rs), trs = jax.lax.scan(body, (st, obs, rs),
-                                       jax.random.split(k1, cap // n))
-        rows = jax.tree.map(lambda x: x.reshape((cap,) + x.shape[2:]), trs)
+        (_, rs), rows = jax.lax.scan(body, (start(k0), rs),
+                                     jax.random.split(k1, cap // n))
+        rows = jax.tree.map(lambda x: x.reshape((cap,) + x.shape[2:]), rows)
         return b.replay.flush(rs), rows
 
     if b.mesh is None:
@@ -219,23 +170,30 @@ def _drive(ex, state, seconds: float, in_flight: int):
     """Run chunks, ``in_flight`` of them queued on the device at a time,
     until ``seconds`` have passed, then wait for all that was sent.  The
     chunks queued behind the one waited for keep the chip fed while the
-    host stands still; losses are read as each chunk completes.  Returns
-    (state, chunks, seconds from the first dispatch to the end of the
-    last chunk, losses)."""
+    host stands still; losses are read as each chunk completes, and the
+    longest time between two completions is logged (a stall of the host
+    longer than the queue shows there).  Returns (state, chunks, seconds
+    from the first dispatch to the end of the last chunk, losses)."""
     import collections
 
     import jax
 
-    t0 = time.perf_counter()
-    pending, chunks, losses = collections.deque(), 0, []
+    t0, last = time.perf_counter(), None
+    pending, chunks, losses, longest = collections.deque(), 0, [], 0.0
     while True:
         while len(pending) < in_flight and time.perf_counter() - t0 < seconds:
             state, m = ex.run_chunk(state)
             pending.append(m)
         if not pending:
+            log(f"window: longest time between chunk completions "
+                f"{longest:.4f} s")
             return state, chunks, time.perf_counter() - t0, losses
         m = pending.popleft()
         jax.block_until_ready(m["loss"])
+        now = time.perf_counter()
+        if last is not None:
+            longest = max(longest, now - last)
+        last = now
         chunks += 1
         losses.append(m["loss"])
 

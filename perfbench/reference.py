@@ -6,7 +6,8 @@ loop's initial state, written out here), takes the buffer's rows as
 input (the benchmark's fill, the way a served model's reference takes
 the prompts), and follows the first iteration's ``learns`` updates:
 inverse-CDF draws over the leaf priorities, gathered rows, PER
-importance weights, the algorithm's loss and gradient, Adam, the
+importance weights, the loss and gradient of the configuration's
+algorithm family (``perfbench/algorithms/``), Adam, the
 Polyak target, and the priority write-back that the next draw sees.
 With several shards each draws from its own buffer against the global
 mass and maximum weight, and the gradients are averaged across shards.
@@ -24,6 +25,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from perfbench import algorithms
+
 
 def seed_key(seed: int) -> np.ndarray:
     """A raw uint32[2] PRNG key that keeps all 64 bits of ``seed``."""
@@ -31,34 +34,12 @@ def seed_key(seed: int) -> np.ndarray:
     return np.array([s >> 32, s & 0xFFFFFFFF], np.uint32)
 
 
-def _mlp_init(key, sizes):
-    ks = jax.random.split(key, len(sizes) - 1)
-    return [{"w": jax.random.normal(ks[i], (a, b)) * (2.0 / (a + b)) ** 0.5,
-             "b": jnp.zeros((b,))}
-            for i, (a, b) in enumerate(zip(sizes[:-1], sizes[1:]))]
-
-
-def _mlp(params, x):
-    for i, layer in enumerate(params):
-        x = jnp.dot(x, layer["w"]) + layer["b"]
-        if i < len(params) - 1:
-            x = jnp.maximum(x, 0)
-    return x
-
-
 def init_params(config: dict, seed: int):
     """Weights as the loop's initial state makes them from ``seed``:
-    key → (env, agent, rng) by a three-way split; DDPG splits the agent
-    key again into policy and critic."""
+    key → (env, agent, rng) by a three-way split; the family makes its
+    weights from the agent key."""
     _, k_agent, _ = jax.random.split(jnp.asarray(seed_key(seed)), 3)
-    h = tuple(config["hidden_sizes"])
-    if config["algorithm"] == "ddpg":
-        kp, kq = jax.random.split(k_agent)
-        return {"pi": _mlp_init(kp, (config["obs_dim"], *h,
-                                     config["action_dim"])),
-                "q": _mlp_init(kq, (config["obs_dim"] + config["action_dim"],
-                                    *h, 1))}
-    return _mlp_init(k_agent, (config["obs_dim"], *h, config["num_actions"]))
+    return algorithms.of(config).init_params(config, k_agent)
 
 
 def sample_keys(seed: int, shard: int, learns: int):
@@ -68,44 +49,6 @@ def sample_keys(seed: int, shard: int, learns: int):
     k = jax.random.fold_in(k, shard)
     _, _, k_sample = jax.random.split(k, 3)
     return [jax.random.fold_in(k_sample, i) for i in range(learns)]
-
-
-def _loss_fn(config: dict):
-    gamma = config["gamma"]
-    if config["algorithm"] == "ddpg":
-        lo, hi = config["action_low"], config["action_high"]
-        scale, mid = (hi - lo) / 2.0, (hi + lo) / 2.0
-
-        def pi(p, obs):
-            return jnp.tanh(_mlp(p, obs)) * scale + mid
-
-        def q(p, obs, act):
-            return _mlp(p, jnp.concatenate([obs, act], -1))[..., 0]
-
-        def loss(params, target, b, w):
-            tgt = b["reward"] + gamma * (1 - b["done"]) * q(
-                target["q"], b["next_obs"], pi(target["pi"], b["next_obs"]))
-            td = q(params["q"], b["obs"], b["action"]) - jax.lax.stop_gradient(tgt)
-            critic = jnp.mean(w * td * td)
-            frozen = jax.lax.stop_gradient(params["q"])
-            actor = -jnp.mean(q(frozen, b["obs"], pi(params["pi"], b["obs"])))
-            return critic + actor, (td, jnp.abs(critic) + jnp.abs(actor))
-        return loss
-
-    def loss(params, target, b, w):
-        q_next_t = _mlp(target, b["next_obs"])
-        if config["double_q"]:
-            sel = jnp.argmax(_mlp(params, b["next_obs"]), axis=-1)
-            v_next = jnp.take_along_axis(q_next_t, sel[:, None], 1)[:, 0]
-        else:
-            v_next = jnp.max(q_next_t, axis=-1)
-        tgt = b["reward"] + gamma * (1.0 - b["done"]) * v_next
-        q_all = _mlp(params, b["obs"])
-        q_sa = jnp.take_along_axis(q_all, b["action"][:, None], 1)[:, 0]
-        td = q_sa - jax.lax.stop_gradient(tgt)
-        loss = jnp.mean(w * td * td)
-        return loss, (td, loss)
-    return loss
 
 
 def _adam(config: dict, grads, m, v, params, count):
@@ -158,7 +101,7 @@ def follow_first_iteration(config: dict, seed: int, rows: List[Dict],
     n_shards = len(rows)
     cap = next(iter(rows[0].values())).shape[0]
     alpha, eps, beta = config["per_alpha"], config["per_eps"], config["per_beta"]
-    loss_fn = _loss_fn(config)
+    loss_fn = algorithms.of(config).loss(config)
     precision = "highest" if dtype == jnp.float32 else "default"
     cast = lambda t: jax.tree.map(lambda x: jnp.asarray(x, dtype), t)
 
@@ -193,9 +136,8 @@ def follow_first_iteration(config: dict, seed: int, rows: List[Dict],
             grads_sum, shard_loss, shard_scale = None, [], []
             for d, (idx, w) in enumerate(draws):
                 b = {k: np.asarray(val[idx]) for k, val in rows[d].items()}
-                b = {k: (jnp.asarray(x) if k == "action" and
-                         config["algorithm"] != "ddpg" else cast(x))
-                     for k, x in b.items()}
+                b = {k: (cast(x) if np.issubdtype(x.dtype, np.floating)
+                         else jnp.asarray(x)) for k, x in b.items()}
                 wd = cast(w / max(w_max, 1e-12))
                 (loss, (td, scale)), g = grad_fn(params, target, b, wd)
                 shard_loss.append(float(loss))

@@ -27,6 +27,19 @@ sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, ROOT)
 
 
+def allow_queue(depth: int):
+    """Let the TPU runtime hold ``depth`` programs per device before the
+    host's next dispatch blocks; its own limit is 32.  The queue a cell
+    keeps (traffic ``chunks_in_flight``, ``trace_chunks``) is data, and
+    32 chunks of the XLA cells' chunks are under a second of work, too
+    little to ride out a host stall.  Set before JAX makes its
+    backends."""
+    import jax
+
+    jax.config.update("jax_pjrt_client_create_options",
+                      {"max_inflight_computations": depth})
+
+
 def require_tpu(n_chips: int):
     import jax
 
@@ -56,6 +69,8 @@ def main(argv=None):
     from perfbench.spec import resolve
 
     cell = resolve(args.workload, ROOT)
+    allow_queue(max(cell.traffic["chunks_in_flight"],
+                    cell.traffic["trace_chunks"]))
     require_tpu(cell.chips)
 
     from repro import compile_cache

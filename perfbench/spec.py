@@ -6,6 +6,9 @@ configuration, a traffic mix or a per-layer metric by adding files and
 entries, with no edit to this code:
 
 * configuration: the ``file`` its entry in ``configs`` gives;
+* algorithm family: ``perfbench/algorithms/<algorithm>.py``, by the
+                 configuration's ``"algorithm"`` (``algorithms/__init__.py``
+                 lists what the module holds);
 * traffic:       ``perfbench/traffic/<traffic>.json``;
 * limits:        ``perfbench/limits/<workload>.json`` (the comparison
                  that decides ``correct``);
@@ -20,6 +23,8 @@ import importlib.util
 import json
 import os
 from typing import Callable, Dict, List
+
+from perfbench import algorithms
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -71,6 +76,8 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
     w = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     entry = configs[w["config"]]
+    config = _load_json(os.path.join(root, entry["file"]))
+    algorithms.load(config["algorithm"])
     pb = os.path.join(root, "perfbench")
     readers = {
         m["name"]: load_reader(os.path.join(pb, "metrics", m["name"] + ".py"))
@@ -78,7 +85,7 @@ def resolve(workload: str, root: str = ROOT) -> Cell:
     return Cell(
         name=workload, chips=int(w["chips"]),
         config_name=w["config"],
-        config=_load_json(os.path.join(root, entry["file"])),
+        config=config,
         traffic_name=w["traffic"],
         traffic=_load_json(os.path.join(pb, "traffic", w["traffic"] + ".json")),
         limits=_load_json(os.path.join(pb, "limits", workload + ".json")),

@@ -10,16 +10,18 @@ if ROOT not in sys.path:
 
 
 def tiny(cell, capacity=2 ** 14):
-    """The cell at a size a CPU test run holds: widths and the traffic's
-    ratio kept, the buffer and the chunk cut."""
+    """The cell at a size a CPU test run holds: the traffic's ratio kept,
+    the buffer and the chunk cut, then the family's own cuts."""
+    from perfbench import algorithms
+
     traffic = dict(cell.traffic, fill_envs=1024, scan_chunk=1)
     if traffic.get("mesh"):
         traffic.update(n_envs=64, batch_size=256)
     else:
         traffic.update(batch_size=64)
-    return dataclasses.replace(
-        cell, config=dict(cell.config, replay_capacity=capacity),
-        traffic=traffic)
+    config = dict(cell.config, replay_capacity=capacity)
+    config, traffic = algorithms.of(config).tiny(config, traffic)
+    return dataclasses.replace(cell, config=config, traffic=traffic)
 
 
 @pytest.fixture

@@ -1,5 +1,5 @@
-"""The harness finds every cell's files by name, picks up a cell added
-as files and one entry, prints the result line's keys, and refuses to
+"""The harness finds every cell's files by name, picks up a cell and an
+algorithm family added as files and one entry each, prints the result line's keys, and refuses to
 measure anywhere but on a TPU."""
 
 import json
@@ -8,11 +8,13 @@ import re
 import shutil
 import subprocess
 import sys
+import textwrap
 import time
 
+import pytest
 from conftest import ROOT
 
-from perfbench import spec
+from perfbench import algorithms, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
@@ -48,19 +50,46 @@ def test_configs_hold_what_the_harness_builds():
         assert set(c["reduced"]) == set(config["reduced"]), c["name"]
         assert set(c["reduced"]) <= set(config), c["name"]
         assert c["source"].split()[0] in config["source"], c["name"]
-        assert config["replay_capacity"] == 2 ** 20
-        # both sources publish two 256-wide hidden layers
-        assert config["hidden_sizes"] == [256, 256]
-        assert "256, 256" in json.dumps(config["published"]) or \
-            "256 -> 256" in json.dumps(config["published"])
+        algorithms.of(config).check_config(config)
+
+
+def test_check_config_refuses_a_cut_width():
+    with open(os.path.join(ROOT, "perfbench/configs/dqn_cartpole.json")) as f:
+        config = json.load(f)
+    dqn = algorithms.of(config)
+    with pytest.raises(ValueError, match="hidden_sizes"):
+        dqn.check_config(dict(config, hidden_sizes=[64, 64]))
+    with pytest.raises(ValueError, match="replay_capacity"):
+        dqn.check_config(dict(config, replay_capacity=2 ** 14))
+
+
+def test_unknown_algorithm_lists_the_families(tmp_path):
+    have = algorithms.names()
+    assert {"dqn", "ddpg"} <= set(have)
+    with pytest.raises(KeyError, match="no algorithm family 'ppo'") as err:
+        algorithms.load("ppo")
+    for name in have:
+        assert repr(name) in str(err.value)
+    root = _checkout(tmp_path)
+    path = root / "perfbench" / "configs" / "dqn_cartpole.json"
+    path.write_text(json.dumps(dict(json.loads(path.read_text()),
+                                    algorithm="ppo")))
+    with pytest.raises(KeyError, match="no algorithm family 'ppo'"):
+        spec.resolve("dqn_cartpole.ratio2.xla", str(root))
+
+
+def _checkout(tmp_path):
+    root = tmp_path / "checkout"
+    shutil.copytree(os.path.join(ROOT, "perfbench"), root / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
+    return root
 
 
 def test_new_cell_needs_only_files_and_one_entry(tmp_path):
     """A later change adds a configuration, a traffic mix, limits and a
     per-layer metric as new files plus one entry each: no code edit."""
-    root = tmp_path / "checkout"
-    shutil.copytree(os.path.join(ROOT, "perfbench"), root / "perfbench")
-    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
+    root = _checkout(tmp_path)
     pb = root / "perfbench"
     config = json.loads((pb / "configs" / "dqn_cartpole.json").read_text())
     config["name"] = "dqn_cartpole_wide"
@@ -90,6 +119,61 @@ def test_new_cell_needs_only_files_and_one_entry(tmp_path):
     assert cell.readers["window_seconds"]({"window_s": 2.5}) == 2.5
     assert "window_seconds" not in spec.resolve(
         "dqn_cartpole.ratio2.xla", str(root)).readers
+
+
+TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy_family")
+
+RUN_TOY = textwrap.dedent("""
+    import json, sys, time
+    sys.path[:0] = [{root!r}, {src!r}]
+    from perfbench import cell as cell_mod, faults
+    from perfbench.spec import resolve
+    cell = resolve("linq_walk.walk")
+    out = {{}}
+    for arm in ("program", "half_batch"):
+        run = lambda: cell_mod.run(cell, 2 ** 31 + 77, 0.2, False,
+                                   cell_mod.CompileClock(),
+                                   time.perf_counter())
+        if arm == "program":
+            out[arm] = run()
+        else:
+            with faults.half_batch():
+                out[arm] = run()
+    print(json.dumps(out))
+""")
+
+
+def test_new_family_needs_only_files_and_one_entry(tmp_path):
+    """A later change adds an algorithm family (its module, with its own
+    env, Q-function, reference loss and work counts), a configuration,
+    a traffic mix and limits as new files plus one entry each.  A whole
+    run of the new cell (the chip check skipped) is correct on the CPU,
+    and not correct with half of each batch left out under the timed
+    path."""
+    root = _checkout(tmp_path)
+    pb = root / "perfbench"
+    for name, dest in (("linq.py", "algorithms"), ("linq_walk.json", "configs"),
+                       ("walk.json", "traffic"),
+                       ("linq_walk.walk.json", "limits")):
+        shutil.copy(os.path.join(TOY, name), pb / dest / name)
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "linq_walk", "source": "test",
+                             "file": "perfbench/configs/linq_walk.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": "linq_walk.walk", "config": "linq_walk",
+                               "traffic": "walk", "chips": 1, "why": "test"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    r = subprocess.run(
+        [sys.executable, "-c", RUN_TOY.format(
+            root=str(root), src=os.path.join(ROOT, "src"))],
+        cwd=str(root), capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=""))
+    assert r.returncode == 0, r.stderr[-3000:]
+    out = json.loads(r.stdout.strip().splitlines()[-1])
+    program, fault = out["program"], out["half_batch"]
+    assert program["correct"] is True, program["checks"]
+    assert program["attempted"] > 0 and program["failed"] == 0
+    assert fault["correct"] is False, fault["checks"]
 
 
 def test_result_line_keys(tiny_cell):

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from perfbench import check, reference
+from perfbench.algorithms import _mlp
 from perfbench.spec import resolve
 
 CAPACITY = 2 ** 14
@@ -31,12 +32,12 @@ def mlp_one_pass(params, x):
 
 @contextlib.contextmanager
 def one_mxu_pass():
-    plain = reference._mlp
-    reference._mlp = mlp_one_pass
+    plain = _mlp.apply
+    _mlp.apply = mlp_one_pass
     try:
         yield
     finally:
-        reference._mlp = plain
+        _mlp.apply = plain
 
 
 def synthetic_rows(config, seed):
